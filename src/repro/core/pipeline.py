@@ -26,6 +26,7 @@ import numpy as np
 from repro.core import packing, precond, quantize, util
 from repro.core.admm import ADMMConfig, QuantizationError
 from repro.core.layout import EXCLUDE_LINEARS, quantizable_linear
+from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.train.optim import AdamW, cosine_schedule
@@ -216,9 +217,11 @@ def _tune(apply_fn, bp, pred, Xq, Y, ctx, steps, lr, mb, weighted,
     opt = AdamW(cosine_schedule(lr, steps), clip_norm=1.0)
     state = opt.init(trainable)
 
-    def loss(tr, xb, yb, cb):
-        out = apply_fn(util.combine(tr, frozen), xb, cb)
-        return _mse(out, yb, weight)
+    # frozen leaves and the weight are arguments, not closed-over
+    # constants: jit embeds a closed-over array in the program text.
+    def loss(tr, fr, w, xb, yb, cb):
+        out = apply_fn(util.combine(tr, fr), xb, cb)
+        return _mse(out, yb, w)
 
     vg = jax.jit(jax.value_and_grad(loss))
     n = Xq.shape[0]
@@ -227,7 +230,7 @@ def _tune(apply_fn, bp, pred, Xq, Y, ctx, steps, lr, mb, weighted,
         i0 = (s * mb) % max(n - mb + 1, 1)
         xb, yb = Xq[i0:i0 + mb], Y[i0:i0 + mb]
         cb = {k: v[i0:i0 + mb] for k, v in ctx.items()}
-        lval, grads = vg(trainable, xb, yb, cb)
+        lval, grads = vg(trainable, frozen, weight, xb, yb, cb)
         trainable, state, _ = opt.update(grads, state, trainable)
         losses.append(float(lval))
     return util.combine(trainable, frozen), losses
@@ -466,7 +469,18 @@ def nanoquant_quantize(params, cfg, calib_batches, qcfg: QuantConfig,
     report. `faults` (a ``quant.faults.QuantFaultPlan``) injects a
     deterministic fault schedule for chaos testing; `heartbeat` is
     called with a short progress string at block/phase boundaries (what
-    ``launch/quantize.py --supervise`` hang detection watches)."""
+    ``launch/quantize.py --supervise`` hang detection watches).
+
+    The quantizer's programs run the packed linears on the jnp path
+    whatever kernel policy is active: scale-only KD differentiates
+    through them, and the Pallas kernels have no VJP."""
+    with kops.kernel_policy(kops.KernelPolicy(mode="ref")):
+        return _quantize(params, cfg, calib_batches, qcfg, verbose,
+                         journal_dir, resume, faults, heartbeat)
+
+
+def _quantize(params, cfg, calib_batches, qcfg, verbose, journal_dir,
+              resume, faults, heartbeat):
     t0 = time.time()
     key = jax.random.PRNGKey(qcfg.seed)
     report: Dict[str, Any] = {"blocks": [], "ranks": {}}
@@ -650,18 +664,20 @@ def _tune_scales_kd(teacher, qparams, cfg, calib_batches, qcfg: QuantConfig,
     opt = AdamW(cosine_schedule(qcfg.lr_glob, qcfg.t_glob), clip_norm=1.0)
     state = opt.init(trainable)
 
-    def loss(tr, batch):
-        qp = util.combine(tr, frozen)
+    # the teacher and the frozen packed leaves are arguments: a
+    # closed-over array would be embedded in the program text (GBs at
+    # published widths)
+    def loss(tr, fr, tp, batch):
+        qp = util.combine(tr, fr)
         hS = T.backbone(qp, cfg, batch["tokens"], batch.get("image_embeds"))
-        hT = T.backbone(teacher, cfg, batch["tokens"],
-                        batch.get("image_embeds"))
-        return _kd_loss_chunked(hS, hT, qp, teacher, cfg, qcfg.kd_temp)
+        hT = T.backbone(tp, cfg, batch["tokens"], batch.get("image_embeds"))
+        return _kd_loss_chunked(hS, hT, qp, tp, cfg, qcfg.kd_temp)
 
     vg = jax.jit(jax.value_and_grad(loss))
     losses = []
     for s in range(qcfg.t_glob):
         b = calib_batches[s % len(calib_batches)]
-        lval, grads = vg(trainable, b)
+        lval, grads = vg(trainable, frozen, teacher, b)
         trainable, state, _ = opt.update(grads, state, trainable)
         losses.append(float(lval))
         if heartbeat is not None and (s % 10 == 0 or s == qcfg.t_glob - 1):

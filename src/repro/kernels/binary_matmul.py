@@ -39,19 +39,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 # ranks above this don't fit a single VMEM block comfortably alongside
 # the unpacked stage-1 tile; fall back to the two-call kernel.
 MAX_FUSED_RANK = 4096
 
 
 def _unpack_tile(packed, rows):
-    """(rows//32, cols) uint32 -> (rows, cols) ±1 f32 (VPU shift/mask)."""
+    """(rows//32, cols) uint32 -> (rows, cols) ±1 f32 (VPU shift/mask).
+
+    The 0/1 bit goes through int32 on its way to f32: Mosaic has no
+    uint32 -> float32 conversion."""
     shifts = jnp.arange(32, dtype=jnp.uint32)[None, :, None]
     bits = (packed[:, None, :] >> shifts) & jnp.uint32(1)
+    bits = bits.astype(jnp.int32)
     return (bits.astype(jnp.float32) * 2.0 - 1.0).reshape(rows, -1)
 
 
@@ -82,8 +82,8 @@ def packed_matmul(x, packed_w, s_k=None, s_n=None, *,
 
     x: (M, K) float; packed_w: (K//32, N) uint32; s_k: (K,); s_n: (N,).
     bm/bn/bk are preferred tiles: K and N tiles are re-fitted to
-    divisors of the operand dims when possible (see kernels.tuning), so
-    pack-aligned operands are never padded at call time.
+    divisors of the operand dims (:func:`repro.kernels.tuning.fit_tile`),
+    so the packed operand is never padded at call time.
     """
     from repro.kernels import tuning
     M, K = x.shape
@@ -96,27 +96,15 @@ def packed_matmul(x, packed_w, s_k=None, s_n=None, *,
         s_n = jnp.ones((N,), jnp.float32)
 
     bm = min(bm, max(8, M))
-    bk = tuning._divisor_tile(K, bk, 32) or min(bk, K)
-    bn = tuning._divisor_tile(N, bn, 8) or min(bn, N)
+    bk = tuning.fit_tile(K, bk, tuning.K_TILE_ALIGN)
+    bn = tuning.fit_tile(N, bn, tuning.N_TILE_ALIGN)
     Mp = -(-M // bm) * bm
-    Kp = -(-K // bk) * bk
-    Np = -(-N // bn) * bn
     if Mp != M:
         x = jnp.pad(x, ((0, Mp - M), (0, 0)))
-    if Kp != K:
-        x = jnp.pad(x, ((0, 0), (0, Kp - K)))
-        packed_w = jnp.pad(packed_w, ((0, (Kp - K) // 32), (0, 0)))
-        s_k = jnp.pad(s_k, (0, Kp - K))
-    if Np != N:
-        packed_w = jnp.pad(packed_w, ((0, 0), (0, Np - N)))
-        s_n = jnp.pad(s_n, (0, Np - N))
-    # note: padded packed words are 0 => unpack to -1, but padded s_k/x rows
-    # are 0 so they contribute 0 to the accumulator. Padded N columns are
-    # sliced off below.
 
-    n_m, n_n, n_k = Mp // bm, Np // bn, Kp // bk
-    sk2 = s_k.reshape(1, Kp)
-    sn2 = s_n.reshape(1, Np)
+    n_m, n_n, n_k = Mp // bm, N // bn, K // bk
+    sk2 = s_k.reshape(1, K)
+    sn2 = s_n.reshape(1, N)
 
     out = pl.pallas_call(
         functools.partial(_kernel, n_k=n_k, bk=bk),
@@ -128,13 +116,14 @@ def packed_matmul(x, packed_w, s_k=None, s_n=None, *,
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((Mp, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="nq_packed_matmul",
         interpret=interpret,
     )(x, packed_w, sk2, sn2)
-    return out[:M, :N]
+    return out[:M]
 
 
 def lowrank_binary_matmul_twocall(x, qv, qu_t, s1, s2, *, interpret=False,
@@ -224,26 +213,15 @@ def fused_lowrank_matmul_grouped(xg, qv_g, qu_g, s1_g, s2_g, rmask_g=None, *,
 
     from repro.kernels import tuning
     bm = min(bm, max(8, M))
-    bk = tuning._divisor_tile(K, bk, 32) or min(bk, K)
-    bn = tuning._divisor_tile(N, bn, 8) or min(bn, N)
+    bk = tuning.fit_tile(K, bk, tuning.K_TILE_ALIGN)
+    bn = tuning.fit_tile(N, bn, tuning.N_TILE_ALIGN)
     Mp = -(-M // bm) * bm
-    Kp = -(-K // bk) * bk
-    Np = -(-N // bn) * bn
     if Mp != M:
         xg = jnp.pad(xg, ((0, 0), (0, Mp - M), (0, 0)))
-    if Kp != K:
-        # padded packed words unpack to -1 but the padded s2 columns are
-        # 0, so stage 1 accumulates exactly 0 from the padding.
-        xg = jnp.pad(xg, ((0, 0), (0, 0), (0, Kp - K)))
-        qv_g = jnp.pad(qv_g, ((0, 0), (0, (Kp - K) // 32), (0, 0)))
-        s2_g = jnp.pad(s2_g, ((0, 0), (0, Kp - K)))
-    if Np != N:
-        qu_g = jnp.pad(qu_g, ((0, 0), (0, 0), (0, Np - N)))
-        s1_g = jnp.pad(s1_g, ((0, 0), (0, Np - N)))
 
-    n_m, n_n, n_k = Mp // bm, Np // bn, Kp // bk
-    s2_3 = s2_g.reshape(G, 1, Kp)
-    s1_3 = s1_g.reshape(G, 1, Np)
+    n_m, n_n, n_k = Mp // bm, N // bn, K // bk
+    s2_3 = s2_g.reshape(G, 1, K)
+    s1_3 = s1_g.reshape(G, 1, N)
     rm_3 = rmask_g.reshape(G, 1, R)
 
     def _k(g, i, s):
@@ -263,7 +241,7 @@ def fused_lowrank_matmul_grouped(xg, qv_g, qu_g, s1_g, s2_g, rmask_g=None, *,
             pl.BlockSpec((1, bm, bk),
                          (lambda g, i, s: (0, i, _k(g, i, s))) if x_shared
                          else (lambda g, i, s: (g, i, _k(g, i, s)))),
-            pl.BlockSpec((1, Kp // 32 // n_k, R_eff),
+            pl.BlockSpec((1, bk // 32, R_eff),
                          lambda g, i, s: (g, _k(g, i, s), 0)),
             pl.BlockSpec((1, R_eff // 32, bn),
                          lambda g, i, s: (g, 0, _j(g, i, s))),
@@ -272,13 +250,14 @@ def fused_lowrank_matmul_grouped(xg, qv_g, qu_g, s1_g, s2_g, rmask_g=None, *,
             pl.BlockSpec((1, 1, R_eff), lambda g, i, s: (g, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, s: (g, i, _j(g, i, s))),
-        out_shape=jax.ShapeDtypeStruct((G, Mp, Np), xg.dtype),
+        out_shape=jax.ShapeDtypeStruct((G, Mp, N), xg.dtype),
         scratch_shapes=[pltpu.VMEM((bm, R_eff), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="nq_fused_lowrank_matmul",
         interpret=interpret,
     )(xg, qv_g, qu_g, s2_3, s1_3, rm_3)
-    return out[:, :M, :N]
+    return out[:, :M]
 
 
 def fused_lowrank_matmul(x, qv, qu_t, s1, s2, *, interpret=False,

@@ -54,22 +54,58 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.binary_matmul import _unpack_tile
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _split_heads(row, head_dim):
+    """(1, H*D) lane-flat projection row -> (H, D) head rows.
+
+    Mosaic cannot reshape a lane dim into (H, D) when D < 128, so the
+    relayout is a 0/1 selection matmul: spread the row over H sublanes,
+    keep each head's own lanes, and gather lane j to column j mod D.
+    At HIGHEST precision the selection is exact."""
+    n = row.shape[-1]
+    h = n // head_dim
+    own = _iota((h, n), 1) // head_dim == _iota((h, n), 0)
+    spread = jnp.where(own, jnp.broadcast_to(row, (h, n)), 0.0)
+    sel = (_iota((n, head_dim), 0) % head_dim
+           == _iota((n, head_dim), 1)).astype(jnp.float32)
+    return jnp.dot(spread, sel, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _merge_heads(heads, width):
+    """(H, D) head rows -> (1, width) lane-flat row (zeros past H*D):
+    the inverse selection matmul of :func:`_split_heads`."""
+    h, d = heads.shape
+    sel_t = (_iota((d, width), 1) % d == _iota((d, width), 0)
+             ).astype(jnp.float32)
+    # wide[h, j] = heads[h, j % d]
+    wide = jnp.dot(heads, sel_t, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+    own = _iota((h, width), 1) // d == _iota((h, width), 0)
+    return jnp.where(own, wide, 0.0).sum(axis=0, keepdims=True)
 
 
 def _rope_rows(h, pos, theta):
-    """Rotate-half RoPE on (H, D) rows at a single traced position."""
+    """Rotate-half RoPE on (H, D) f32 rows at a single traced position.
+    rotate_half is a signed permutation matmul (exact at HIGHEST
+    precision): Mosaic has no lane concatenation of D/2-wide halves."""
     d = h.shape[-1]
-    half = jax.lax.broadcasted_iota(jnp.float32, (1, d // 2), 1)
-    inv = 1.0 / (theta ** (2.0 * half / d))              # (1, D/2)
+    half = d // 2
+    j = _iota((1, d), 1)
+    freq = jnp.where(j < half, j, j - half).astype(jnp.float32)
+    inv = 1.0 / (theta ** (2.0 * freq / d))              # (1, D)
     ang = pos.astype(jnp.float32) * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    hf = h.astype(jnp.float32)
-    x1, x2 = hf[:, : d // 2], hf[:, d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1)
+    # rot[:, c] = -h[:, c + D/2] for c < D/2, h[:, c - D/2] otherwise
+    src, dst = _iota((d, d), 0), _iota((d, d), 1)
+    perm = (jnp.where((dst < half) & (src == dst + half), -1.0, 0.0)
+            + jnp.where((dst >= half) & (src == dst - half), 1.0, 0.0))
+    rot = jnp.dot(h, perm, precision=jax.lax.Precision.HIGHEST,
+                  preferred_element_type=jnp.float32)
+    return h * jnp.cos(ang) + rot * jnp.sin(ang)
 
 
 def _stage1(x_row, qv_ref, sel, r_eff, bk):
@@ -91,8 +127,8 @@ def _stage2(t_acc, qu_ref, s1_ref, sel, n, r_eff, bn):
         u = _unpack_tile(qu_ref[sel + (slice(None),
                                        pl.ds(nt * bn, bn))], r_eff)
         ys.append(jnp.dot(t_acc, u, preferred_element_type=jnp.float32)
-                  * s1_ref[sel + (pl.ds(nt * bn, bn),)
-                           ].astype(jnp.float32)[None])
+                  * s1_ref[pl.ds(sel[0], 1), pl.ds(nt * bn, bn)
+                           ].astype(jnp.float32))
     return jnp.concatenate(ys, axis=1)
 
 
@@ -119,20 +155,21 @@ def _kernel(bt_ref, qpos_ref, cpos_ref, x_ref, qv3_ref, qu3_ref, s23_ref,
         outs = []
         for g, n in enumerate((nq, nkv, nkv)):
             xg = (x_ref[0].astype(jnp.float32)
-                  * s23_ref[g].astype(jnp.float32))[None]      # (1, K)
+                  * s23_ref[pl.ds(g, 1), :].astype(jnp.float32))  # (1, K)
             t_acc = _stage1(xg, qv3_ref, (g,), r_eff, bk)
-            t_acc = t_acc * rm3_ref[g].astype(jnp.float32)[None]
+            t_acc = t_acc * rm3_ref[pl.ds(g, 1), :].astype(jnp.float32)
             y_g = _stage2(t_acc, qu3_ref, s13_ref, (g,),
                           s13_ref.shape[-1], r_eff, bn)
             # round to the activation dtype — the unfused chain's
             # projection output dtype — before RoPE/scoring.
-            outs.append(y_g[0, :n].astype(x_dtype))
-        q = _rope_rows(outs[0].reshape(hq, head_dim), pos, theta)
+            outs.append(_split_heads(
+                y_g[:, :n].astype(x_dtype).astype(jnp.float32), head_dim))
+        q = _rope_rows(outs[0], pos, theta)
         q_s[...] = q.astype(x_dtype).astype(jnp.float32)
-        k = _rope_rows(outs[1].reshape(hkv, head_dim), pos, theta)
+        k = _rope_rows(outs[1], pos, theta)
         kn_ref[0] = k.astype(x_dtype).astype(kn_ref.dtype)
         k_s[...] = kn_ref[0].astype(jnp.float32)
-        vn_ref[0] = outs[2].reshape(hkv, head_dim).astype(vn_ref.dtype)
+        vn_ref[0] = outs[2].astype(vn_ref.dtype)
         v_s[...] = vn_ref[0].astype(jnp.float32)
 
     @pl.when(jnp.logical_and(t >= 1, t <= n_steps))
@@ -181,15 +218,14 @@ def _kernel(bt_ref, qpos_ref, cpos_ref, x_ref, qv3_ref, qu3_ref, s23_ref,
                + p_new[..., None] * v_s[...][:, None, :])
         o = acc / jnp.maximum(l, 1e-30)[..., None]       # (Hkv, G, D)
         # wo while the attention output is still in VMEM
-        ko = s2o_ref.shape[-1]
-        xo = o.reshape(1, nq).astype(x_dtype).astype(jnp.float32)
-        if ko != nq:
-            xo = jnp.pad(xo, ((0, 0), (0, ko - nq)))
-        xo = xo * s2o_ref[0].astype(jnp.float32)[None]
+        # (stored K may be pack-aligned past Hq*D; padded s2 cols are 0)
+        xo = _merge_heads(o.reshape(hq, head_dim).astype(x_dtype)
+                          .astype(jnp.float32), s2o_ref.shape[-1])
+        xo = xo * s2o_ref[...].astype(jnp.float32)
         t_o = _stage1(xo, qvo_ref, (0,), ro_eff, bko)
         y = _stage2(t_o, quo_ref, s1o_ref, (0,), s1o_ref.shape[-1],
                     ro_eff, bno)
-        y_ref[0] = y[0].astype(y_ref.dtype)
+        y_ref[0] = y.astype(y_ref.dtype)
 
 
 def decode_step_megakernel_raw(x, mqkv, wo, k_pool, v_pool, block_table,
@@ -228,10 +264,10 @@ def decode_step_megakernel_raw(x, mqkv, wo, k_pool, v_pool, block_table,
     if rmask is None:
         rmask = jnp.ones((3, R), jnp.float32)
 
-    bk = tuning._divisor_tile(K, bk, 32) or K
-    bn_q = tuning._divisor_tile(Nmax, bn, 8) or Nmax
-    bko = tuning._divisor_tile(Ko, bk, 32) or Ko
-    bno = tuning._divisor_tile(No, bn, 8) or No
+    bko = tuning.fit_tile(Ko, bk, tuning.K_TILE_ALIGN)
+    bk = tuning.fit_tile(K, bk, tuning.K_TILE_ALIGN)
+    bn_q = tuning.fit_tile(Nmax, bn, tuning.N_TILE_ALIGN)
+    bno = tuning.fit_tile(No, bn, tuning.N_TILE_ALIGN)
 
     ppb = max(1, min(int(pages_per_step), pages))
     npad = -(-pages // ppb) * ppb
@@ -259,7 +295,7 @@ def decode_step_megakernel_raw(x, mqkv, wo, k_pool, v_pool, block_table,
         num_scalar_prefetch=3,
         grid=(B, T),
         in_specs=[
-            pl.BlockSpec((1, K), lambda b, t, bt_, qp, cp: (b, 0)),
+            pl.BlockSpec((1, 1, K), lambda b, t, bt_, qp, cp: (b, 0, 0)),
             # rank sub-extents: eff_rank truncation without repacking
             pl.BlockSpec((3, K // 32, r_eff), const(0, 0, 0)),
             pl.BlockSpec((3, r_eff // 32, Nmax), const(0, 0, 0)),
@@ -273,7 +309,7 @@ def decode_step_megakernel_raw(x, mqkv, wo, k_pool, v_pool, block_table,
             *kv_specs,
         ],
         out_specs=[
-            pl.BlockSpec((1, No), lambda b, t, bt_, qp, cp: (b, 0)),
+            pl.BlockSpec((1, 1, No), lambda b, t, bt_, qp, cp: (b, 0, 0)),
             pl.BlockSpec((1, hkv, head_dim),
                          lambda b, t, bt_, qp, cp: (b, 0, 0)),
             pl.BlockSpec((1, hkv, head_dim),
@@ -296,16 +332,17 @@ def decode_step_megakernel_raw(x, mqkv, wo, k_pool, v_pool, block_table,
             ro_eff=ro_eff, bk=bk, bn=bn_q, bko=bko, bno=bno),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, No), x.dtype),
+            jax.ShapeDtypeStruct((B, 1, No), x.dtype),
             jax.ShapeDtypeStruct((B, hkv, head_dim), k_pool.dtype),
             jax.ShapeDtypeStruct((B, hkv, head_dim), v_pool.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name="nq_decode_megakernel",
         interpret=interpret,
     )(bt, q_pos.astype(jnp.int32), cache_pos.astype(jnp.int32),
-      x, mqkv["qv"], mqkv["qu_t"], mqkv["s2"], mqkv["s1"],
+      x[:, None], mqkv["qv"], mqkv["qu_t"], mqkv["s2"], mqkv["s1"],
       rmask.astype(jnp.float32), wo["qv"][None], wo["qu_t"][None],
       wo["s2"].reshape(1, Ko), wo["s1"].reshape(1, No),
       *([k_pool, v_pool] * ppb))
-    return y, k_new, v_new
+    return y[:, 0], k_new, v_new

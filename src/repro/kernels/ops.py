@@ -241,15 +241,14 @@ def _shard_launch(p: KernelPolicy, local, in_specs, out_specs, *operands,
     each device runs `local(*operands_shard, local_policy)` on its
     shard (the local policy is the same policy with the mesh stripped),
     optionally finishing with one psum over `reduce_axis`."""
-    from repro.sharding.rules import shard_map_compat
     lp = dataclasses.replace(p, mesh=None)
 
     def body(*ops_):
         y = local(*ops_, lp)
         return jax.lax.psum(y, reduce_axis) if reduce_axis else y
 
-    return shard_map_compat(body, p.mesh, in_specs=in_specs,
-                            out_specs=out_specs)(*operands)
+    return jax.shard_map(body, mesh=p.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*operands)
 
 
 def _tp_lowrank(x, qv, qu_t, s1, s2, p: KernelPolicy, role: str,
@@ -461,13 +460,12 @@ def paged_attention(q, k_pool, v_pool, block_table, q_pos, cache_pos, *,
             return _local_paged_attention(q_, kp_, vp_, bt_, qp_, cp_,
                                           window, scale, lp)
 
-        from repro.sharding.rules import shard_map_compat
-        return shard_map_compat(
-            body, p.mesh,
+        return jax.shard_map(
+            body, mesh=p.mesh,
             in_specs=(P(None, None, ax, None), P(None, None, ax, None),
                       P(None, None, ax, None), P(None, None), P(None),
                       P(None)),
-            out_specs=P(None, None, ax, None))(
+            out_specs=P(None, None, ax, None), check_vma=False)(
                 q, k_pool, v_pool, block_table, q_pos, cache_pos)
     return _local_paged_attention(q, k_pool, v_pool, block_table, q_pos,
                                   cache_pos, window, scale, p)

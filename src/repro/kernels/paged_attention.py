@@ -47,10 +47,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _online_update(s, msk, v, m_ref, l_ref, acc_ref):
     """One online-softmax step: fold scores ``s`` (Hb, G, R) with mask
@@ -182,8 +178,9 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, q_pos,
                           ppb=ppb, n_steps=n_steps),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, Hq, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        name="nq_paged_attention",
         interpret=interpret,
     )(bt, q_pos.astype(jnp.int32), cache_pos.astype(jnp.int32),
       q, *([k_pool, v_pool] * ppb))

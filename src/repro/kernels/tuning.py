@@ -8,11 +8,12 @@ Two layers per kernel family:
   per :class:`~repro.kernels.ops.KernelPolicy` (``block_table=...``).
 - :func:`fit_block_sizes` — fits the table's *preferred* tile sizes to a
   concrete shape so that the K and N tiles **divide** the operand dims
-  whenever they are pack-aligned. This is what lets ``packed_matmul`` /
-  the fused kernel skip call-time padding of the packed weights: a
-  divisor tile means zero pad ops traced into the jitted decode step
-  (the old code padded K up to a fixed bk=512 multiple, copying the
-  whole packed tensor once per token for shapes like d_ff=2816).
+  and keep Mosaic's (8, 128) block tiling. This is what lets
+  ``packed_matmul`` / the fused kernel skip call-time padding of the
+  packed weights: a divisor tile means zero pad ops traced into the
+  jitted decode step (padding K up to a fixed bk=512 multiple would
+  copy the whole packed tensor once per token for shapes like
+  d_ff=2816).
 
 A shape no table row covers is never silently given the generic prefill
 tile: :func:`lookup_block_table` falls back to shape-derived preferred
@@ -41,9 +42,14 @@ from typing import Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 
-# sign bits are packed 32-per-word along K; K tiles must stay multiples
-# of 32 so a tile maps to whole uint32 rows of the packed operand.
+# sign bits are packed 32-per-word along K, so a K tile of bk rows is a
+# (bk // 32)-row block of the packed operand. Mosaic tiles the last two
+# dims of every block by (8 sublanes, 128 lanes) unless the block spans
+# the whole dim: a packed K tile needs whole 8-row sublane groups (bk a
+# multiple of 8 * 32 = 256) and an N tile whole lanes (a multiple of 128).
 PACK_ALIGN = 32
+K_TILE_ALIGN = 8 * PACK_ALIGN
+N_TILE_ALIGN = 128
 
 # (m_hi, k_hi, n_hi, r_hi, bm, bn, bk) — seeded by the offline sweep in
 # benchmarks/kernel_wallclock.py (--sweep); ordered decode -> prefill.
@@ -86,18 +92,18 @@ def _sublane(dtype) -> int:
     return 8
 
 
-def _divisor_tile(dim: int, pref: int, align: int) -> int:
+def fit_tile(dim: int, pref: int, align: int) -> int:
     """Largest multiple of `align` that divides `dim` and is <= `pref`;
-    0 when `dim` has no aligned divisor (caller falls back to padding)."""
-    if dim % align:
-        return 0
+    the whole `dim` when there is none (a full-extent block is always
+    legal). Either way the tile divides `dim`, so operands are never
+    padded at call time."""
     best = 0
     d = align
     while d <= min(pref, dim):
         if dim % d == 0:
             best = d
         d += align
-    return best
+    return best or dim
 
 
 # shape classes that already warned a table miss (once per process per
@@ -149,18 +155,16 @@ def fit_block_sizes(M: int, K: int, N: int, r: int, dtype=jnp.float32,
                     ) -> Tuple[int, int, int]:
     """Concrete (bm, bn, bk) for one kernel call.
 
-    K/N tiles are fitted to divisors of the operand dims whenever the
-    dim is pack-aligned, so the packed weights are never padded at call
-    time; the M tile covers the (small) activation batch rounded to the
-    dtype sublane. Only a dim with no aligned divisor (e.g. an N not a
-    multiple of 8; K is always 32-aligned by packing) falls back to the
-    preferred tile with call-time padding.
+    K/N tiles divide the operand dims (:func:`fit_tile`: bk a multiple
+    of 256, bn a multiple of 128, else the whole dim), so the packed
+    weights are never padded at call time; the M tile covers the
+    (small) activation batch rounded to the dtype sublane.
     """
     bm_p, bn_p, bk_p = lookup_block_table(M, K, N, r, table)
     sub = _sublane(dtype)
     bm = min(max(bm_p, sub), -(-M // sub) * sub)
-    bk = _divisor_tile(K, bk_p, PACK_ALIGN) or min(bk_p, K)
-    bn = _divisor_tile(N, bn_p, 8) or min(bn_p, N)
+    bk = fit_tile(K, bk_p, K_TILE_ALIGN)
+    bn = fit_tile(N, bn_p, N_TILE_ALIGN)
     return bm, bn, bk
 
 

@@ -5,6 +5,10 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
 """Multi-pod dry-run: AOT lower + compile every (arch x shape x mesh)
 cell and record memory / cost / collective analysis.
 
+A CPU-only tool: it forces 512 placeholder host devices before JAX
+starts. On a machine with a TPU, run it under ``JAX_PLATFORMS=cpu`` so
+that it neither claims the chip nor competes with the process using it.
+
     PYTHONPATH=src python -m repro.launch.dryrun \
         --arch qwen3-4b --shape train_4k --mesh both --out experiments/dryrun
 

@@ -26,16 +26,8 @@ def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...]):
             f"mesh {shape} needs {need} devices, found {len(devs)} — "
             f"set XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             f"before the first jax import (dryrun.py does this)")
-    try:
-        kwargs = {}
-        if hasattr(jax.sharding, "AxisType"):   # absent in older jax
-            kwargs["axis_types"] = (
-                jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, devices=devs[:need], **kwargs)
-    except TypeError:  # older jax without devices/axis_types kwargs
-        from jax.experimental import mesh_utils
-        arr = mesh_utils.create_device_mesh(shape, devices=devs[:need])
-        return jax.sharding.Mesh(arr, axes)
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

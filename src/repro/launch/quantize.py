@@ -26,6 +26,7 @@ import sys
 
 from repro import api
 from repro.data import SyntheticCorpus, calib_batches, train_iterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import TrainConfig, Trainer
 
 HEARTBEAT_RE = r"\[quant\] heartbeat"
@@ -95,6 +96,7 @@ def main():
             hang_timeout=args.hang_timeout,
             heartbeat_pattern=HEARTBEAT_RE))
 
+    enable_compile_cache()
     cfg = api.get_config(args.arch) if args.full else api.get_smoke(args.arch)
     tcfg = TrainConfig(lr=1e-3, warmup=20, total_steps=args.teacher_steps)
 

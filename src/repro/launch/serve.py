@@ -32,6 +32,7 @@ import numpy as np
 
 from repro import api
 from repro.data import calib_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serve import recovery
 
@@ -124,6 +125,7 @@ def main():
             hang_timeout=args.hang_timeout,
             heartbeat_pattern=r"\[serve\] heartbeat"))
 
+    enable_compile_cache()
     if args.quantized_ckpt and not args.fp:
         model = api.NanoQuantModel.load(args.quantized_ckpt)
         print(f"[serve] loaded artifact {args.quantized_ckpt} "
@@ -244,6 +246,11 @@ def main():
         first = min(done)
         print(f"[serve] sample output for request {first}: "
               f"{done[first].output[:16]}")
+    if eng.stats["failed"]:
+        # a failed request may be a kernel that did not compile on this
+        # device (the engine isolates any prefill exception); never
+        # report that run as a success
+        sys.exit(f"[serve] {eng.stats['failed']} request(s) failed")
 
 
 def _print_pool_stats(eng) -> None:

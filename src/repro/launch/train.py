@@ -19,6 +19,7 @@ import jax
 from repro import configs
 from repro.checkpoint import CheckpointManager
 from repro.data import train_iterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import TrainConfig, Trainer, make_train_step
 
 
@@ -76,6 +77,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    enable_compile_cache()
     tr = build_trainer(args)
     tr.restore_or_init()
     remaining = args.steps - tr.step

@@ -149,8 +149,6 @@ def analyse_compiled(compiled, lowered_text: Optional[str] = None,
     for comparison."""
     from repro.roofline.hlo import module_cost
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):            # older jax returns [dict]
-        cost = cost[0] if cost else {}
     xla_flops = float(cost.get("flops", 0.0))
     xla_bytes = float(cost.get("bytes accessed", 0.0))
     text = lowered_text if lowered_text is not None else compiled.as_text()

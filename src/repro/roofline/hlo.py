@@ -483,3 +483,19 @@ def top_contributors(text: str, k: int = 25):
     by_bytes = sorted(rows, key=lambda r: -(r[4] * r[2]))[:k]
     return ([fmt(*r) for r in by_flops if r[3] > 0],
             [fmt(*r) for r in by_bytes if r[4] > 0])
+
+
+_PALLAS_TPU_CALL = re.compile(
+    r"%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"")
+
+
+def pallas_kernels(text: str) -> Dict[str, int]:
+    """Pallas TPU kernels in compiled HLO text: how many
+    ``tpu_custom_call`` instructions carry each kernel name (the
+    ``name=`` of its ``pallas_call``; this repo's are ``nq_*``). Counts
+    instructions, not executions: a kernel inside a layer scan counts
+    once."""
+    counts: Dict[str, int] = {}
+    for m in _PALLAS_TPU_CALL.finditer(text):
+        counts[m.group(1)] = counts.get(m.group(1), 0) + 1
+    return counts

@@ -7,10 +7,10 @@ these placements.
 """
 from repro.sharding.rules import (  # noqa: F401
     batch_pspecs, cache_pspecs, data_axes, param_pspecs, replicate_specs,
-    shard_map_compat, to_shardings, tp_role, ShardingPolicy, DEFAULT, SERVE)
+    to_shardings, tp_role, ShardingPolicy, DEFAULT, SERVE)
 
 __all__ = [
     "ShardingPolicy", "DEFAULT", "SERVE",
     "param_pspecs", "batch_pspecs", "cache_pspecs", "replicate_specs",
-    "to_shardings", "data_axes", "tp_role", "shard_map_compat",
+    "to_shardings", "data_axes", "tp_role",
 ]

@@ -103,18 +103,6 @@ def tp_role(name) -> Optional[str]:
     return None
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax releases (moved out of jax.experimental;
-    check_rep renamed check_vma). Replication checks are disabled: the
-    kernel launches below psum explicitly where reduction is needed."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def _fit(dim: int, axis, mesh: Mesh):
     """axis if dim divides evenly over it, else None."""
     return axis if axis is not None and dim % _axis_size(mesh, axis) == 0 \

@@ -95,7 +95,7 @@ def _operands(rng, m, k, n, r, dtype):
 # ===========================================================================
 
 
-@settings(max_examples=_examples(20))
+@settings(max_examples=_examples(20), deadline=None)
 @given(seed=SEEDS)
 def test_matmul_fused_vs_twocall_vs_oracle(seed):
     rng = np.random.default_rng(seed)
@@ -116,7 +116,7 @@ def test_matmul_fused_vs_twocall_vs_oracle(seed):
     _close("twocall-vs-oracle", oracle, two, _tol(dtype), seed, **case)
 
 
-@settings(max_examples=_examples(15))
+@settings(max_examples=_examples(15), deadline=None)
 @given(seed=SEEDS)
 def test_matmul_eff_rank_truncation(seed):
     """Rank-truncated launches (the speculative draft forward) read only
@@ -137,7 +137,7 @@ def test_matmul_eff_rank_truncation(seed):
     _close("effrank", want, got, 1e-5, seed, **case)
 
 
-@settings(max_examples=_examples(15))
+@settings(max_examples=_examples(15), deadline=None)
 @given(seed=SEEDS)
 def test_matmul_offblock_shapes(seed):
     """K values the preferred bk=512 tile does NOT divide (the
@@ -155,7 +155,7 @@ def test_matmul_offblock_shapes(seed):
     _close("offblock", want, got, 1e-5, seed, **case)
 
 
-@settings(max_examples=_examples(15))
+@settings(max_examples=_examples(15), deadline=None)
 @given(seed=SEEDS)
 def test_merged_rmask_vs_oracle(seed):
     """Grouped QKV-style launch with ragged true ranks (rmask) equals
@@ -238,7 +238,7 @@ def _paged_policy(ppb, hb):
         PALLAS, paged_block_table=((BIG, BIG, BIG, BIG, ppb, hb),))
 
 
-@settings(max_examples=_examples(15))
+@settings(max_examples=_examples(15), deadline=None)
 @given(seed=SEEDS)
 def test_paged_attention_vs_oracle(seed):
     rng = np.random.default_rng(seed)
@@ -252,7 +252,7 @@ def test_paged_attention_vs_oracle(seed):
     _close("paged", want, got, _tol(dtype), seed, **case)
 
 
-@settings(max_examples=_examples(15))
+@settings(max_examples=_examples(15), deadline=None)
 @given(seed=SEEDS)
 def test_paged_attention_multitoken_vs_oracle(seed):
     """S in {1..5} multi-token verify reads (all S rows pre-written,
@@ -360,7 +360,7 @@ def _unfused_chain(x, mqkv, wo, kp, vp, bt, qpos, cpos, ppb, *, dims,
     return y, k[:, 0], v[:, 0]
 
 
-@settings(max_examples=_examples(8))
+@settings(max_examples=_examples(8), deadline=None)
 @given(seed=SEEDS)
 def test_megakernel_vs_unfused_chain_vs_oracle(seed):
     rng = np.random.default_rng(seed)

@@ -215,10 +215,13 @@ def test_kernel_policy_switch():
 
 
 def test_policy_block_table_override():
-    table = ((100_000, 100_000, 100_000, 100_000, 16, 64, 64),)
+    table = ((100_000, 100_000, 100_000, 100_000, 16, 128, 256),)
     pol = ops.KernelPolicy(block_table=table)
-    bm, bn, bk = pol.block_sizes(8, 256, 192, 32)
-    assert (bm, bn, bk) == (8, 64, 64)     # bm covers M=8 at sublane 8
+    bm, bn, bk = pol.block_sizes(8, 1024, 512, 32)
+    assert (bm, bn, bk) == (8, 128, 256)   # bm covers M=8 at sublane 8
+    # a dim with no aligned divisor under the preferred tile takes the
+    # whole dim: a full-extent block is the only other legal tile
+    assert pol.block_sizes(8, 704, 192, 32) == (8, 192, 704)
     # default policy: decode shape gets a sublane-sized M tile
     bm, bn, bk = ops.KernelPolicy().block_sizes(8, 2048, 2048, 512)
     assert bm == 8
@@ -229,8 +232,11 @@ def test_block_size_fitting_divisors():
     # K=2816 (llama-style d_ff) misaligns to the old fixed bk=512; the
     # fitter must pick a divisor tile so no weight padding is traced
     bm, bn, bk = tuning.fit_block_sizes(8, 2816, 1024, 256)
-    assert 2816 % bk == 0 and bk % 32 == 0
-    assert 1024 % bn == 0
+    assert 2816 % bk == 0 and bk % 256 == 0
+    assert 1024 % bn == 0 and bn % 128 == 0
+    # ... and the same width as N: a 128-multiple divisor, never 352
+    _, bn, _ = tuning.fit_block_sizes(8, 1024, 2816, 256)
+    assert 2816 % bn == 0 and bn % 128 == 0
     # bf16 activations need 16 sublanes
     bm16, _, _ = tuning.fit_block_sizes(4, 256, 256, 32, jnp.bfloat16)
     assert bm16 == 16

@@ -60,15 +60,10 @@ def test_compressed_psum_matches_mean():
 
         # output is replicated by construction (all-gather then identical
         # local math) but vma inference can't prove it -> disable the
-        # replication check (kwarg renamed check_rep -> check_vma, and
-        # shard_map moved out of jax.experimental, across jax releases)
-        if hasattr(jax, 'shard_map'):
-            shard_map, kw = jax.shard_map, {'check_vma': False}
-        else:
-            from jax.experimental.shard_map import shard_map
-            kw = {'check_rep': False}
-        f = shard_map(lambda gs: compressed_psum(gs[0], 'data', ccfg),
-                      mesh=mesh, in_specs=P('data'), out_specs=P(), **kw)
+        # replication check
+        f = jax.shard_map(lambda gs: compressed_psum(gs[0], 'data', ccfg),
+                          mesh=mesh, in_specs=P('data'), out_specs=P(),
+                          check_vma=False)
         got = f(g)
         want = jnp.mean(g, axis=0)
         rel = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
