@@ -29,6 +29,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels import ops as kops
 from repro.models import transformer as T
@@ -495,7 +496,7 @@ class InferenceEngine:
 
         def prefill_fn(params, tokens, last_idx):
             self.stats["prefill_traces"] += 1
-            with self._trace_scope():
+            with self._policy_scope():
                 return slot_prefill(params, tokens, last_idx)
         self._prefill = jax.jit(prefill_fn)
 
@@ -505,7 +506,7 @@ class InferenceEngine:
         # the admission-sized sibling of the speculative S>1 verify.
         def suffix_fn(params, tokens, start, last_idx, cache, table):
             self.stats["prefill_traces"] += 1
-            with self._trace_scope():
+            with self._policy_scope():
                 return T.prefill(params, cfg, tokens, cache,
                                  last_idx=last_idx, start_pos=start,
                                  block_tables={"linear": table})
@@ -528,7 +529,7 @@ class InferenceEngine:
 
         def decode_fn(params, tokens, cache, pos, active, key, tables):
             self.stats["decode_traces"] += 1
-            with self._trace_scope():
+            with self._policy_scope():
                 logits, new_cache = T.decode_step(params, cfg, tokens,
                                                   cache, pos,
                                                   block_tables=tables)
@@ -546,12 +547,13 @@ class InferenceEngine:
             self.spec = SpecDecodeController(self)
 
     @contextlib.contextmanager
-    def _trace_scope(self):
-        """Tracing context for the jitted steps. Scopes in this engine's
-        kernel policy (the ambient policy, plus the ServeConfig's
-        megakernel override and — with a mesh — the mesh for shard_map
-        TP kernel launches) and, with a mesh, activation-sharding
-        constraints. Both are contextvar-based, so concurrent traces
+    def _policy_scope(self):
+        """JAX trace-time context for the jitted steps (not a profiler
+        span: those are the ``serve.*`` TraceAnnotations of `step`).
+        Scopes in this engine's kernel policy (the ambient policy, plus
+        the ServeConfig's megakernel override and — with a mesh — the
+        mesh for shard_map TP kernel launches) and, with a mesh,
+        activation-sharding constraints. Both are contextvar-based, so concurrent traces
         from other engines or training cells are untouched, and dispatch
         is baked into the traced computation (execution needs no ambient
         globals)."""
@@ -634,12 +636,59 @@ class InferenceEngine:
         User `on_token` callbacks fire only after every slot's engine
         state (positions, budgets, cache, completion bookkeeping) has
         been committed for the tick — a raising callback cannot leave
-        the engine inconsistent (the exception still propagates)."""
-        finished = []
-        self._callbacks = []
-        if self.faults is not None:
-            self.faults.on_step(self)
-        self._reap()
+        the engine inconsistent (the exception still propagates).
+
+        The tick and its parts are host spans for the JAX profiler
+        (``serve.tick`` and its ``serve.*`` children, docs/serving.md
+        §Observability); without a running profiler they cost about a
+        microsecond each."""
+        with TraceAnnotation("serve.tick", step=self.stats["steps"]):
+            finished = []
+            self._callbacks = []
+            if self.faults is not None:
+                self.faults.on_step(self)
+            with TraceAnnotation("serve.reap"):
+                self._reap()
+            for slot, handle in self.scheduler.admit_batch(
+                    self._admission_gate()):
+                fin = self._admit(slot, handle)
+                if fin is not None:
+                    finished.append(fin)
+            if self.prefix is not None:
+                self.prefix.unprotect_all()
+            self.stats["peak_active"] = max(self.stats["peak_active"],
+                                            int(self.active.sum()))
+            if self.active.any():
+                t0 = time.monotonic()
+                try:
+                    if self.spec is not None:
+                        with TraceAnnotation("serve.spec_cycle"):
+                            self.spec.tick(finished)
+                    else:
+                        self._decode_tick(finished)
+                except _InjectedDeviceError as e:
+                    self._on_device_fault(e)
+                self.stats["decode_time_s"] += time.monotonic() - t0
+            self.stats["steps"] += 1
+            if self.scfg.debug:
+                self.check_invariants()
+            callbacks, self._callbacks = self._callbacks, []
+            err = None
+            with TraceAnnotation("serve.callbacks"):
+                for cb, uid, token in callbacks:
+                    try:
+                        cb(uid, token)
+                    except BaseException as e:  # deliver to every consumer,
+                        err = err or e          # then surface the first one
+            if err is not None:
+                raise err
+            return finished
+
+    def _admission_gate(self) -> Optional[Callable[[Any], bool]]:
+        """This tick's admission check for `scheduler.admit_batch`: free
+        pages above the watermark (paged), no fresh work while draining,
+        and the fault plan's gate hook. None admits whatever fits a
+        slot."""
         gate = None
         if self.paged:
             promised = [0]     # pages owed to earlier admissions in this
@@ -688,37 +737,7 @@ class InferenceEngine:
                     # match and kv.admit — protection must hold it
                     self.faults.on_gate(self)
                 return page_gate(item) if page_gate is not None else True
-        for slot, handle in self.scheduler.admit_batch(gate):
-            fin = self._admit(slot, handle)
-            if fin is not None:
-                finished.append(fin)
-        if self.prefix is not None:
-            self.prefix.unprotect_all()
-        self.stats["peak_active"] = max(self.stats["peak_active"],
-                                        int(self.active.sum()))
-        if self.active.any():
-            t0 = time.monotonic()
-            try:
-                if self.spec is not None:
-                    self.spec.tick(finished)
-                else:
-                    self._decode_tick(finished)
-            except _InjectedDeviceError as e:
-                self._on_device_fault(e)
-            self.stats["decode_time_s"] += time.monotonic() - t0
-        self.stats["steps"] += 1
-        if self.scfg.debug:
-            self.check_invariants()
-        callbacks, self._callbacks = self._callbacks, []
-        err = None
-        for cb, uid, token in callbacks:
-            try:
-                cb(uid, token)
-            except BaseException as e:     # deliver to every consumer,
-                err = err or e             # then surface the first error
-        if err is not None:
-            raise err
-        return finished
+        return gate
 
     def run(self) -> Dict[int, Request]:
         """Drain the queue; returns {uid: completed Request}."""
@@ -875,30 +894,34 @@ class InferenceEngine:
         jitted decode, commit positions and emit. Shared by the plain
         step and the speculative controller's k<1 fallback."""
         if self.paged:
-            self._ensure_decode_pages()
+            with TraceAnnotation("serve.reserve_pages"):
+                self._ensure_decode_pages()
         if not self.active.any():          # everything self-preempted
             return
         if self.faults is not None:
             # raises _InjectedDeviceError *before* the donated device
             # call, so the pool buffer is still valid for recovery
             self.faults.before_decode(self)
-        tables = self.kv.device_tables() if self.paged else {}
-        self.key, k = jax.random.split(self.key)
-        tok, self.cache = self._decode(
-            self.params, jnp.asarray(self.tokens), self.cache,
-            jnp.asarray(self.pos), jnp.asarray(self.active), k, tables)
-        tok = np.array(tok)        # writable copy: slots mutate it
+        with TraceAnnotation("serve.decode_dispatch"):
+            tables = self.kv.device_tables() if self.paged else {}
+            self.key, k = jax.random.split(self.key)
+            tok, self.cache = self._decode(
+                self.params, jnp.asarray(self.tokens), self.cache,
+                jnp.asarray(self.pos), jnp.asarray(self.active), k, tables)
+        with TraceAnnotation("serve.decode_wait"):
+            tok = np.array(tok)    # writable copy: slots mutate it
         self.tokens = tok
         self.stats["decode_steps"] += 1
         self.stats["wasted_slot_steps"] += int(
             self.max_batch - self.active.sum())
-        for slot in range(self.max_batch):
-            if not self.active[slot]:
-                continue
-            self.pos[slot] += 1
-            fin = self._emit(slot, tok[slot][0])
-            if fin is not None:
-                finished.append(fin)
+        with TraceAnnotation("serve.emit"):
+            for slot in range(self.max_batch):
+                if not self.active[slot]:
+                    continue
+                self.pos[slot] += 1
+                fin = self._emit(slot, tok[slot][0])
+                if fin is not None:
+                    finished.append(fin)
 
     def reset_stats(self) -> None:
         for k in ("steps", "decode_steps", "wasted_slot_steps",
@@ -917,10 +940,16 @@ class InferenceEngine:
                   "shared_pages", "cow_copies", "evicted_pages",
                   # failure handling (docs/serving.md §Failure handling):
                   # terminal-status counters + recovered device errors
-                  "cancelled", "expired", "failed", "device_faults"):
+                  "cancelled", "expired", "failed", "device_faults",
+                  # admission prefill: real prompt rows prefilled (fresh
+                  # and resumed; a prefix hit counts its suffix) and the
+                  # rows their compiled buckets computed — the
+                  # difference is padding
+                  "prefill_rows", "prefill_bucket_rows"):
             self.stats[k] = 0
-        # host wall-clock spent in the decode/spec device step + commit
-        # (benchmarks divide tokens_emitted by this for decode tok/s)
+        # host wall-clock spent in the decode/spec device step + commit,
+        # admission excluded (serve_bench's decode_tok_s divides output
+        # tokens by it)
         self.stats["decode_time_s"] = 0.0
 
     def kv_cache_bytes(self) -> int:
@@ -966,17 +995,19 @@ class InferenceEngine:
         page-exactly and the other slots keep decoding. Page-accounting
         violations stay engine-fatal: broken pool bookkeeping cannot be
         attributed to one request."""
-        try:
-            return self._admit_impl(slot, item)
-        except paging.PageAccountingError:
-            raise
-        except _AbortAdmission as e:       # cancel/expire mid-prefill
-            self._teardown_admission(slot, item, e.status, e.reason)
-        except Exception as e:
-            self._teardown_admission(slot, item, "failed",
-                                     f"{type(e).__name__}: {e}")
-            self.check_invariants()        # every fault audits the pool
-        return None
+        with TraceAnnotation("serve.admit", uid=self._item_handle(item).uid,
+                             slot=slot) as span:
+            try:
+                return self._admit_impl(slot, item, span)
+            except paging.PageAccountingError:
+                raise
+            except _AbortAdmission as e:   # cancel/expire mid-prefill
+                self._teardown_admission(slot, item, e.status, e.reason)
+            except Exception as e:
+                self._teardown_admission(slot, item, "failed",
+                                         f"{type(e).__name__}: {e}")
+                self.check_invariants()    # every fault audits the pool
+            return None
 
     def _teardown_admission(self, slot: int, item, status: str,
                             reason: str) -> None:
@@ -993,10 +1024,20 @@ class InferenceEngine:
         toks = item.emitted if isinstance(item, _Resume) else []
         self._finalize_aborted(handle, status, reason, toks)
 
-    def _admit_impl(self, slot: int, item) -> Optional[Request]:
+    def _count_prefill(self, span: TraceAnnotation, rows: int,
+                       bucket: int) -> None:
+        """Record an admission prefill of `rows` real rows in a compiled
+        bucket of `bucket` rows: the counters and the admit span's args."""
+        self.stats["prefill_rows"] += int(rows)
+        self.stats["prefill_bucket_rows"] += int(bucket)
+        span.set_metadata(rows=int(rows), bucket=int(bucket))
+
+    def _admit_impl(self, slot: int, item,
+                    span: TraceAnnotation) -> Optional[Request]:
         """Prefill `item`'s prompt into `slot` and emit its next token.
-        `item` is a fresh RequestHandle or a preempted _Resume. Returns
-        the request if it finished immediately."""
+        `item` is a fresh RequestHandle or a preempted _Resume; `span` is
+        its ``serve.admit`` span. Returns the request if it finished
+        immediately."""
         if isinstance(item, _Resume):
             handle, prompt = item.handle, item.prompt
             budget_cap, prior = item.budget, item.emitted
@@ -1013,43 +1054,54 @@ class InferenceEngine:
             # speculative rollback cost are directly comparable.
             self.stats["preempt_recompute_tokens"] += int(n)
         hit = (0, [])
-        if self.prefix is not None:
-            # match fresh (not the gate's estimate): an earlier _admit
-            # in this same batch may have registered chunks this prompt
-            # can now share. Gate-matched entries are protected, so the
-            # fresh match only ever covers MORE than the gate promised
-            # pages for — and kv.admit refs the pages immediately, with
-            # no reclaim possible in between (same host thread).
-            p, pages, _ = self.prefix.match(prompt)
-            hit = (p, pages)
-            self.stats["prefix_lookup_tokens"] += int(n)
-            self.stats["prefix_hit_tokens"] += int(p)
-        if hit[0] > 0:
-            logits = self._admit_shared(slot, prompt, n, *hit)
-        else:
-            if self.cfg.is_ssm_layer_stack:
-                # right-padding would leak pad tokens into the recurrent
-                # SSM/conv state, so SSM-stack families prefill at the
-                # exact prompt length (one compile per distinct length).
-                bucket = n
+        with TraceAnnotation("serve.prefill", uid=req.uid):
+            if self.prefix is not None:
+                # match fresh (not the gate's estimate): an earlier
+                # _admit in this same batch may have registered chunks
+                # this prompt can now share. Gate-matched entries are
+                # protected, so the fresh match only ever covers MORE
+                # than the gate promised pages for — and kv.admit refs
+                # the pages immediately, with no reclaim possible in
+                # between (same host thread).
+                p, pages, _ = self.prefix.match(prompt)
+                hit = (p, pages)
+                self.stats["prefix_lookup_tokens"] += int(n)
+                self.stats["prefix_hit_tokens"] += int(p)
+            if hit[0] > 0:
+                logits = self._admit_shared(slot, prompt, n, *hit,
+                                            span=span)
             else:
-                bucket = bucket_length(n, self.max_len)
-            padded = np.zeros((1, bucket) + prompt.shape[1:], np.int32)
-            padded[0, :n] = prompt
-            logits, single = self._prefill(self.params, jnp.asarray(padded),
-                                           jnp.asarray(n - 1, jnp.int32))
-            if self.paged:
-                ids = self.kv.admit(slot, n)       # gated by admit_batch
-                self.cache = self._insert(
-                    self.cache, single, jnp.asarray(slot, jnp.int32),
-                    {k: jnp.asarray(v) for k, v in ids.items()})
-            else:
-                self.cache = self._insert(self.cache, single,
-                                          jnp.asarray(slot, jnp.int32))
+                if self.cfg.is_ssm_layer_stack:
+                    # right-padding would leak pad tokens into the
+                    # recurrent SSM/conv state, so SSM-stack families
+                    # prefill at the exact prompt length (one compile
+                    # per distinct length).
+                    bucket = n
+                else:
+                    bucket = bucket_length(n, self.max_len)
+                self._count_prefill(span, n, bucket)
+                padded = np.zeros((1, bucket) + prompt.shape[1:], np.int32)
+                padded[0, :n] = prompt
+                logits, single = self._prefill(
+                    self.params, jnp.asarray(padded),
+                    jnp.asarray(n - 1, jnp.int32))
+        if hit[0] == 0:
+            with TraceAnnotation("serve.insert", uid=req.uid):
+                if self.paged:
+                    ids = self.kv.admit(slot, n)   # gated by admit_batch
+                    self.cache = self._insert(
+                        self.cache, single, jnp.asarray(slot, jnp.int32),
+                        {k: jnp.asarray(v) for k, v in ids.items()})
+                else:
+                    self.cache = self._insert(self.cache, single,
+                                              jnp.asarray(slot, jnp.int32))
         if self.faults is not None \
                 and self.faults.poison_prefill(self, req.uid):
             logits = jnp.full_like(logits, jnp.nan)
-        if not bool(jnp.isfinite(logits.astype(jnp.float32)).all()):
+        # the host waits here for the prefill (and the insert behind it)
+        with TraceAnnotation("serve.prefill_wait", uid=req.uid):
+            finite = bool(jnp.isfinite(logits.astype(jnp.float32)).all())
+        if not finite:
             # checked BEFORE prefix.register: NaN logits mean the
             # prefilled KV is suspect too, and a registered chunk would
             # poison every future sharer of those pages
@@ -1085,16 +1137,18 @@ class InferenceEngine:
         return fin
 
     def _admit_shared(self, slot: int, prompt: np.ndarray, n: int,
-                      p: int, pages: List[int]) -> jnp.ndarray:
+                      p: int, pages: List[int], *,
+                      span: TraceAnnotation) -> jnp.ndarray:
         """Prefix-hit admission: map the `p` matched tokens' pages
         (`pages`) read-only into `slot` and prefill only the uncached
         suffix directly into the pool (the start-offset prefill path).
         A full-cover match (p == n) still re-emits from the last prompt
         token, so its row is copy-on-written first and exactly one
         token is re-prefilled. Returns the next-token logits."""
-        self.kv.admit(slot, n, shared=pages)
-        start = n - 1 if p == n else p
-        ok = self._cow_rows(slot, start, n)
+        with TraceAnnotation("serve.insert", uid=self.scheduler.slots[slot]):
+            self.kv.admit(slot, n, shared=pages)
+            start = n - 1 if p == n else p
+            ok = self._cow_rows(slot, start, n)
         assert ok, "admission COW starved: gate promised the page"
         suffix = prompt[start:]
         ps = self.kv.page_size
@@ -1103,6 +1157,7 @@ class InferenceEngine:
         # table_width * page_size) and trash the shared prefix pages
         bucket = min(bucket_length(suffix.shape[0], self.max_len),
                      self.kv.lin_pages * ps - start)
+        self._count_prefill(span, suffix.shape[0], bucket)
         padded = np.zeros((1, bucket) + prompt.shape[1:], np.int32)
         padded[0, :suffix.shape[0]] = suffix
         table = jnp.asarray(self.kv.tables["linear"][slot:slot + 1])
@@ -1186,18 +1241,19 @@ class InferenceEngine:
         of its generation as a _Resume. Its handle keeps streaming —
         emitted tokens are never replayed."""
         task = self._tasks[slot]
-        emitted = np.asarray(task.toks, np.int32)
-        prompt = np.concatenate(
-            [np.asarray(task.handle.request.prompt, np.int32), emitted],
-            axis=0)
-        self.active[slot] = False
-        self._tasks[slot] = None
-        self.slot_of.pop(task.handle.uid, None)   # queued, not placed
-        self.kv.release(slot)
-        self.scheduler.release(slot)
-        self.scheduler.requeue(_Resume(task.handle, prompt, task.budget,
-                                       list(task.toks)))
-        self.stats["preemptions"] += 1
+        with TraceAnnotation("serve.preempt", uid=task.handle.uid):
+            emitted = np.asarray(task.toks, np.int32)
+            prompt = np.concatenate(
+                [np.asarray(task.handle.request.prompt, np.int32), emitted],
+                axis=0)
+            self.active[slot] = False
+            self._tasks[slot] = None
+            self.slot_of.pop(task.handle.uid, None)   # queued, not placed
+            self.kv.release(slot)
+            self.scheduler.release(slot)
+            self.scheduler.requeue(_Resume(task.handle, prompt, task.budget,
+                                           list(task.toks)))
+            self.stats["preemptions"] += 1
 
     def _emit(self, slot: int, token) -> Optional[Request]:
         """Record one emitted token for `slot`; finish the slot on EOS

@@ -145,7 +145,7 @@ class SpecDecodeController:
 
         def cycle(params, draft, tokens, cache, pos, active, tables):
             eng.stats["decode_traces"] += 1
-            with eng._trace_scope():
+            with eng._policy_scope():
                 def body(carry, _):
                     tok, c, p = carry
                     lg, c = T.decode_step(draft, cfg, tok, c, p,

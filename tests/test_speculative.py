@@ -490,7 +490,7 @@ def test_bf16_tp2_argmax_divergence_rate():
                                       max_len=S + 1, mesh=m)
 
                 def fwd(p, t, cache):
-                    with eng._trace_scope():
+                    with eng._policy_scope():
                         h, _ = T._cached_forward(p, cfg, t, cache, 0)
                         return T.logits_fn(p, cfg, h)
 
