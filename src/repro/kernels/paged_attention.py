@@ -16,7 +16,7 @@ Two tuning knobs (``kernels.tuning.fit_paged_block_sizes``):
   page DMAs are issued together off one scalar-prefetched block-table
   read (coalesced) and the per-step grid overhead amortizes across the
   group. The block table is padded with null-page entries up to a
-  multiple; padded entries mask out.
+  multiple; padded entries mask out (and sit past every live bound).
 - ``head_block`` — kv-head tile (0 = all heads in one block). A divisor
   of Hkv adds a head grid dimension with per-tile online-softmax
   scratch, for models whose (Hkv, G, D) state would crowd VMEM.
@@ -31,6 +31,17 @@ Unmapped block-table entries point at the null page 0 and mask out
 because their virtual rows sit past every valid position; padded
 table entries sit past the virtual rectangle entirely and are masked
 explicitly.
+
+Groups past a slot's live bound are skipped, not just masked. The
+wrapper derives each slot's live group count from ``q_pos`` and
+``cache_pos`` (:func:`live_steps`, a fourth scalar-prefetch operand):
+every group once the slot has written the whole table or its live rows
+wrap the ring, else up to the group of row ``cache_pos``. The page
+index maps clamp the step to the slot's last live group, so the block
+index repeats and the pipeline issues no copy for the dead steps, and
+the body runs under ``pl.when(j < live)``. A dead group holds only
+masked rows, so it would have left the online-softmax state exactly as
+it was: the skip changes no bit of the output.
 
 Numerics are validated against :func:`repro.kernels.ref.
 paged_attention_ref` on the CPU interpreter (tests/test_paging.py and
@@ -64,9 +75,9 @@ def _online_update(s, msk, v, m_ref, l_ref, acc_ref):
     m_ref[...] = m_new
 
 
-def _kernel(bt_ref, qpos_ref, cpos_ref, q_ref, *rest, pages: int,
-            page_size: int, window: int, scale: float, ppb: int,
-            n_steps: int):
+def _kernel(bt_ref, qpos_ref, cpos_ref, live_ref, q_ref, *rest,
+            pages: int, page_size: int, window: int, scale: float,
+            ppb: int, n_steps: int):
     kv_refs = rest[:2 * ppb]
     o_ref, m_ref, l_ref, acc_ref = rest[2 * ppb:]
     b = pl.program_id(0)
@@ -78,36 +89,60 @@ def _kernel(bt_ref, qpos_ref, cpos_ref, q_ref, *rest, pages: int,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                  # (Hb*G, D)
-    hqb, d = q.shape
+    hqb, d = q_ref.shape[2], q_ref.shape[3]
     hb = kv_refs[0].shape[2]
-    qg = q.reshape(hb, hqb // hb, d)                     # (Hb, G, D)
     rows = pages * page_size
 
-    # the group's pages arrive as ppb separate VMEM blocks whose DMAs
-    # were all issued from this step's block-table prefetch; the online
-    # softmax carries across the widened page axis within the step.
-    for i in range(ppb):
-        k = kv_refs[2 * i][0].astype(jnp.float32)        # (PS, Hb, D)
-        v = kv_refs[2 * i + 1][0].astype(jnp.float32)
-        s = jax.lax.dot_general(                         # (Hb, G, PS)
-            qg, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale
+    # page groups past the slot's live bound hold only masked rows: they
+    # would leave (m, l, acc) exactly as they are, so they are skipped
+    # (their index maps repeat the last live group, so no DMA either).
+    @pl.when(j < live_ref[b])
+    def _walk():
+        q = q_ref[0, 0].astype(jnp.float32)              # (Hb*G, D)
+        qg = q.reshape(hb, hqb // hb, d)                 # (Hb, G, D)
 
-        # virtual-row validity (see module docstring)
-        p_idx = j * ppb + i
-        r = p_idx * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page_size), 2)
-        abs_pos = qpos_ref[b] - (cpos_ref[b] - r) % rows
-        msk = jnp.logical_and(abs_pos >= 0, p_idx < pages)
-        if window:
-            msk = jnp.logical_and(msk, abs_pos > qpos_ref[b] - window)
-        _online_update(s, msk, v, m_ref, l_ref, acc_ref)
+        # the group's pages arrive as ppb separate VMEM blocks whose
+        # DMAs were all issued from this step's block-table prefetch;
+        # the online softmax carries across the widened page axis
+        # within the step.
+        for i in range(ppb):
+            k = kv_refs[2 * i][0].astype(jnp.float32)    # (PS, Hb, D)
+            v = kv_refs[2 * i + 1][0].astype(jnp.float32)
+            s = jax.lax.dot_general(                     # (Hb, G, PS)
+                qg, k, (((2,), (2,)), ((0,), (1,))),
+                preferred_element_type=jnp.float32) * scale
+
+            # virtual-row validity (see module docstring)
+            p_idx = j * ppb + i
+            r = p_idx * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, page_size), 2)
+            abs_pos = qpos_ref[b] - (cpos_ref[b] - r) % rows
+            msk = jnp.logical_and(abs_pos >= 0, p_idx < pages)
+            if window:
+                msk = jnp.logical_and(msk, abs_pos > qpos_ref[b] - window)
+            _online_update(s, msk, v, m_ref, l_ref, acc_ref)
 
     @pl.when(j == n_steps - 1)
     def _flush():
         o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
         o_ref[0, 0] = o.reshape(hqb, d).astype(o_ref.dtype)
+
+
+def live_steps(q_pos, cache_pos, *, pages: int, page_size: int,
+               ppb: int):
+    """Page groups of each slot's table that hold a valid row, (B,)
+    int32, at least 1. Row ``r`` is valid iff ``(cache_pos - r) mod
+    rows <= q_pos``: every row once ``q_pos >= rows - 1`` or once the
+    live rows wrap (``cache_pos < q_pos``, the sliding-window ring);
+    otherwise rows ``cache_pos - q_pos .. cache_pos``, so the last live
+    page is ``cache_pos // page_size``."""
+    rows = pages * page_size
+    q_pos = q_pos.astype(jnp.int32)
+    cache_pos = cache_pos.astype(jnp.int32)
+    live_pages = jnp.where(
+        (q_pos >= rows - 1) | (cache_pos < q_pos), pages,
+        jnp.minimum(pages, cache_pos // page_size + 1))
+    return jnp.maximum(1, -(-live_pages // ppb)).astype(jnp.int32)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_table, q_pos,
@@ -144,10 +179,20 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, q_pos,
         bt = jnp.pad(bt, ((0, 0), (0, npad - pages)))
     n_steps = npad // ppb
 
+    live = live_steps(q_pos, cache_pos, pages=pages, page_size=PS,
+                      ppb=ppb)
+
     def _kv_map(i):
-        def f(b, h, j, bt_, qp, cp):
-            return (bt_[b, j * ppb + i], 0, h, 0)
+        # past the slot's live bound the step index is clamped to its
+        # last live group: the block index repeats, so the pipeline
+        # issues no new copy for the dead steps.
+        def f(b, h, j, bt_, qp, cp, lv):
+            jj = jnp.minimum(j, lv[b] - 1)
+            return (bt_[b, jj * ppb + i], 0, h, 0)
         return f
+
+    def _q_map(b, h, j, bt_, qp, cp, lv):
+        return (b, 0, h, 0)
 
     kv_specs = []
     for i in range(ppb):
@@ -155,17 +200,15 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, q_pos,
         kv_specs.append(pl.BlockSpec((1, PS, hb, D), _kv_map(i)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, n_h, n_steps),
         in_specs=[
             # q heads are kv-head-major (GQA group g of kv head h is
             # head h*G+g), so a kv-head tile's queries are contiguous.
-            pl.BlockSpec((1, 1, hb * G, D),
-                         lambda b, h, j, bt_, qp, cp: (b, 0, h, 0)),
+            pl.BlockSpec((1, 1, hb * G, D), _q_map),
             *kv_specs,
         ],
-        out_specs=pl.BlockSpec((1, 1, hb * G, D),
-                               lambda b, h, j, bt_, qp, cp: (b, 0, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, hb * G, D), _q_map),
         scratch_shapes=[
             pltpu.VMEM((hb, G), jnp.float32),            # running max
             pltpu.VMEM((hb, G), jnp.float32),            # running sum
@@ -182,5 +225,5 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, q_pos,
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         name="nq_paged_attention",
         interpret=interpret,
-    )(bt, q_pos.astype(jnp.int32), cache_pos.astype(jnp.int32),
+    )(bt, q_pos.astype(jnp.int32), cache_pos.astype(jnp.int32), live,
       q, *([k_pool, v_pool] * ppb))

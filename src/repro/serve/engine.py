@@ -914,6 +914,13 @@ class InferenceEngine:
         self.stats["decode_steps"] += 1
         self.stats["wasted_slot_steps"] += int(
             self.max_batch - self.active.sum())
+        if self.paged and self.kv.has_linear:
+            # the gather walks each slot's linear table up to its live
+            # bound, inactive slots included (their stale pos)
+            tp = self.kv.lin_pages
+            self.stats["gather_pages_live"] += int(np.minimum(
+                tp, self.pos // self.kv.page_size + 1).sum())
+            self.stats["gather_pages_table"] += self.max_batch * tp
         with TraceAnnotation("serve.emit"):
             for slot in range(self.max_batch):
                 if not self.active[slot]:
@@ -945,7 +952,11 @@ class InferenceEngine:
                   # and resumed; a prefix hit counts its suffix) and the
                   # rows their compiled buckets computed — the
                   # difference is padding
-                  "prefill_rows", "prefill_bucket_rows"):
+                  "prefill_rows", "prefill_bucket_rows",
+                  # paged gather: linear-table pages the decode read
+                  # walked (each slot up to its live bound) and the
+                  # pages of the whole tables
+                  "gather_pages_live", "gather_pages_table"):
             self.stats[k] = 0
         # host wall-clock spent in the decode/spec device step + commit,
         # admission excluded (serve_bench's decode_tok_s divides output

@@ -462,6 +462,163 @@ def test_paged_kernel_multitoken_exact_page_boundary():
 
 
 # ---------------------------------------------------------------------------
+# live bound: page groups past a slot's last valid row are skipped
+# ---------------------------------------------------------------------------
+
+LB_PS, LB_PAGES, LB_HKV, LB_G, LB_D = 4, 6, 4, 2, 16
+LB_ROWS = LB_PS * LB_PAGES
+
+
+def _lb_policy(ppb, hb):
+    from repro.kernels import ops as kops
+    return kops.KernelPolicy(mode="pallas", interpret=True,
+                             paged_block_table=((10 ** 6,) * 4 + (ppb, hb),))
+
+
+def _lb_positions(mode, ppb, S):
+    """Ragged per-slot (q_pos, cache_pos) on the live bound's edges."""
+    edges = np.array([0, LB_PS - 1, LB_PS, ppb * LB_PS - 1, ppb * LB_PS,
+                      LB_ROWS - 1])
+    if mode == "linear":                 # the last edge is a full table
+        q = np.minimum(edges, LB_ROWS - S)
+        return q, q
+    if mode == "behind":                 # cache_pos < q_pos: rows wrap
+        q = np.array([2, 7, 9, 13, 18, 21])
+        return q, np.array([0, 3, 4, 12, 1, 20])
+    q = LB_ROWS + np.minimum(edges, LB_ROWS - S)     # sliding-window ring
+    return q, q % LB_ROWS
+
+
+@pytest.mark.parametrize("ppb,hb,S,mode,window", [
+    (1, 0, 1, "linear", 0),
+    (2, 0, 1, "linear", 0),
+    (4, 2, 1, "linear", 0),              # padded table, head tiles
+    (3, 2, 1, "linear", 5),              # windowed linear
+    (2, 0, 1, "behind", 0),
+    (2, 2, 1, "ring", 10),
+    (2, 0, 3, "linear", 0),              # speculative verify spans
+    (4, 2, 4, "linear", 0),
+    (3, 0, 2, "ring", 10),
+])
+def test_paged_kernel_live_bound_matches_ref(ppb, hb, S, mode, window):
+    """The kernel at ragged per-slot lengths on the live bound's edges
+    (q_pos 0, PS-1, PS, ppb*PS-1, ppb*PS, a full table) equals the
+    oracle; linear slots map the null page past their last written
+    row, as the engine does."""
+    from repro.kernels import ops as kops
+    q_pos, cache_pos = _lb_positions(mode, ppb, S)
+    B = len(q_pos)
+    rng = np.random.default_rng(100 * ppb + 10 * S + hb)
+    NP = B * LB_PAGES + 1
+    shape = (NP, LB_PS, LB_HKV, LB_D)
+    kp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, S, LB_HKV * LB_G, LB_D)),
+                    jnp.float32)
+    bt = rng.permutation(np.arange(1, NP)).reshape(B, LB_PAGES)
+    if mode == "linear":
+        last = (q_pos + S - 1) // LB_PS
+        bt[np.arange(LB_PAGES)[None, :] > last[:, None]] = 0
+    bt, q_pos, cache_pos = (jnp.asarray(a, jnp.int32)
+                            for a in (bt, q_pos, cache_pos))
+    got = kops.paged_attention(q, kp, vp, bt, q_pos, cache_pos,
+                               window=window, scale=0.25,
+                               policy=_lb_policy(ppb, hb))
+    want = ref.paged_attention_ref(q, kp, vp, bt, q_pos, cache_pos,
+                                   window=window, scale=0.25)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("ppb", [1, 2, 4])
+def test_live_steps_bound_every_valid_row(ppb):
+    """Every group past `live_steps` holds only masked rows, and on a
+    linear cache the last live group holds a valid one (the bound is
+    tight), by brute force over the ring reconstruction."""
+    from repro.kernels.paged_attention import live_steps
+    r = np.arange(LB_ROWS)
+    qs, cs = np.meshgrid(np.arange(-1, 3 * LB_ROWS),
+                         np.arange(-1, 2 * LB_ROWS))
+    qs, cs = qs.ravel(), cs.ravel()
+    live = np.asarray(live_steps(jnp.asarray(qs), jnp.asarray(cs),
+                                 pages=LB_PAGES, page_size=LB_PS, ppb=ppb))
+    valid = qs[:, None] - (cs[:, None] - r[None, :]) % LB_ROWS >= 0
+    group = r // (ppb * LB_PS)
+    assert not (valid & (group[None, :] >= live[:, None])).any()
+    assert (live >= 1).all()
+    lin = (qs == cs) & (qs >= 0) & (qs < LB_ROWS)
+    assert (valid & (group[None, :] == live[:, None] - 1))[lin].any(1).all()
+
+
+@pytest.mark.parametrize("ppb,hb", [(1, 0), (2, 2), (4, 0)])
+def test_paged_kernel_skips_dead_groups(ppb, hb):
+    """Real pages holding NaN, mapped past each slot's live bound: the
+    oracle (and a kernel that read them) is poisoned through `p @ v`.
+    The kernel neither copies their groups (the clamped index map) nor
+    computes on them (the skipped body), so its output is finite and
+    equals the oracle over the same pool with those pages zeroed."""
+    q_pos = np.array([0, 5, 9, 13])
+    B = len(q_pos)
+    rng = np.random.default_rng(ppb + hb)
+    NP = B * LB_PAGES + 1
+    shape = (NP, LB_PS, LB_HKV, LB_D)
+    kp = rng.standard_normal(shape).astype(np.float32)
+    vp = rng.standard_normal(shape).astype(np.float32)
+    q = jnp.asarray(rng.standard_normal((B, 1, LB_HKV * LB_G, LB_D)),
+                    jnp.float32)
+    bt = np.arange(1, NP).reshape(B, LB_PAGES)
+    live_pages = np.maximum(1, -(-(q_pos // LB_PS + 1) // ppb)) * ppb
+    dead = np.arange(LB_PAGES)[None, :] >= live_pages[:, None]
+    assert dead.any(), "every case holds a dead group"
+    kz, vz = kp.copy(), vp.copy()
+    kp[bt[dead]] = np.nan
+    vp[bt[dead]] = np.nan
+    kz[bt[dead]] = 0.0
+    vz[bt[dead]] = 0.0
+    bt, qp = jnp.asarray(bt, jnp.int32), jnp.asarray(q_pos, jnp.int32)
+    got = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp), bt,
+                                 qp, qp, scale=0.25, pages_per_step=ppb,
+                                 head_block=hb, interpret=True)
+    poisoned = ref.paged_attention_ref(q, jnp.asarray(kp), jnp.asarray(vp),
+                                       bt, qp, qp, scale=0.25)
+    want = ref.paged_attention_ref(q, jnp.asarray(kz), jnp.asarray(vz),
+                                   bt, qp, qp, scale=0.25)
+    assert np.isnan(np.asarray(poisoned)[dead.any(1)]).all()
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_engine_counts_gather_walk(served_model):
+    """`gather_pages_live` / `gather_pages_table` per decode step, over
+    both slots (a finished slot keeps its stale pos), against positions
+    reckoned by hand: page_size 8, max_len 32, so 4 table pages."""
+    cfg, params = served_model
+    eng = InferenceEngine(params, cfg, ServeConfig(greedy=True, page_size=8),
+                          max_batch=2, max_len=32)
+    seen = []
+    decode = eng._decode
+
+    def spy(params, tokens, cache, pos, *rest):
+        seen.append(np.asarray(pos).tolist())
+        return decode(params, tokens, cache, pos, *rest)
+    eng._decode = spy
+    for uid, (p, b) in enumerate(zip(_prompts(cfg, [5, 11, 20]),
+                                     [3, 8, 4])):
+        eng.submit(Request(uid, p, max_new_tokens=b))
+    eng.run()
+    # uid 0 (5 rows, 3 tokens) decodes at 5, 6; uid 2 takes its slot and
+    # decodes at 20..22, then the slot idles at 23; uid 1 decodes 11..17
+    assert seen == [[5, 11], [6, 12], [20, 13], [21, 14], [22, 15],
+                    [23, 16], [23, 17]]
+    live = sum(min(4, p // 8 + 1) for step in seen for p in step)
+    assert live == 33
+    assert eng.stats["gather_pages_live"] == live
+    assert eng.stats["gather_pages_table"] == len(seen) * 2 * 4
+    assert eng.stats["decode_steps"] == len(seen)
+
+
+# ---------------------------------------------------------------------------
 # tensor-parallel paged engine (forced host devices, subprocess)
 # ---------------------------------------------------------------------------
 
