@@ -41,10 +41,12 @@ def readings(workload: str, seed: int, seconds: float, *, bench=None,
     conf = conf or spec.config(w["config"])
     cellp = cellp or spec.cell(workload)
     mix = mix or spec.traffic(w["traffic"])
-    device.check(w["chips"] if chips is None else chips, need_accelerator)
+    chips = w["chips"] if chips is None else chips
+    device.check(chips, need_accelerator)
     device.enable_compile_cache()
+    mesh = serve.serving_mesh(chips)
     t = time.perf_counter()
-    engine = serve.build_engine(conf, cellp, seed)
+    engine = serve.build_engine(conf, cellp, seed, mesh)
     serve.warm(engine, serve.warm_lengths(mix, cellp))
     driver = serve.Driver(engine, btraffic.schedule(mix, cellp.get("rate")),
                           seed, cellp.get("clients"))
@@ -59,9 +61,9 @@ def readings(workload: str, seed: int, seconds: float, *, bench=None,
     n = check.tokens(samples)
     out = {"workload": workload, "seed": seed}
     for who, gap in (("program", check.served_gap(conf, cellp, seed,
-                                                  samples)),
+                                                  samples, mesh)),
                      ("control", check.control_gap(conf, cellp, seed,
-                                                   samples))):
+                                                   samples, mesh))):
         checks, correct = check.judge(cellp, gap, n, compiles, failed)
         out[who] = {"correct": correct, "checks": checks}
     out["requests"] = len(samples)

@@ -63,11 +63,11 @@ def _jitted(ref_name: str, conf_json: str, what: str):
     return jax.jit(functools.partial(ref.control_gaps, conf))
 
 
-def _gaps(conf, cellp, seed, samples, what):
+def _gaps(conf, cellp, seed, samples, what, mesh):
     import json
     import jax
     fn = _jitted(conf["reference"], json.dumps(conf, sort_keys=True), what)
-    w = bmodel.make_weights(conf, seed)
+    w = bmodel.make_weights(conf, seed, mesh)
     widest = []
     for prompt, served in samples:
         tokens, targets = _inputs(prompt, served, cellp["max_len"])
@@ -80,19 +80,23 @@ def _gaps(conf, cellp, seed, samples, what):
     return widest
 
 
-def served_gap(conf: dict, cellp: dict, seed: int, samples) -> float:
-    """The widest gap over every sampled served token (None if none)."""
+def served_gap(conf: dict, cellp: dict, seed: int, samples,
+               mesh=None) -> float:
+    """The widest gap over every sampled served token (None if none).
+    With the cell's mesh, the reference reads the weights drawn into
+    the same shards as the program's and runs over the same chips."""
     if not samples:
         return None
-    return max(_gaps(conf, cellp, seed, samples, "served"))
+    return max(_gaps(conf, cellp, seed, samples, "served", mesh))
 
 
-def control_gap(conf: dict, cellp: dict, seed: int, samples) -> float:
+def control_gap(conf: dict, cellp: dict, seed: int, samples,
+                mesh=None) -> float:
     """The same reading for the fp8 control's first choices at the same
     positions of the same prompts and tokens."""
     if not samples:
         return None
-    return max(_gaps(conf, cellp, seed, samples, "control"))
+    return max(_gaps(conf, cellp, seed, samples, "control", mesh))
 
 
 def tokens(samples) -> int:

@@ -50,6 +50,33 @@ def enable_compile_cache() -> str:
     return path
 
 
+def flush_writes(dirs, since: float) -> int:
+    """fsync every file under `dirs` modified at or after `since` (wall
+    clock), so that what set-up wrote (a first run's compile cache, the
+    runtime's logs) is on the disk before the window opens, and its
+    write-back does not fall inside the window. Returns the files
+    synced."""
+    n = 0
+    for d in dirs:
+        for root, _, files in os.walk(d):
+            for name in files:
+                path = os.path.join(root, name)
+                try:
+                    if os.stat(path).st_mtime < since:
+                        continue
+                    fd = os.open(path, os.O_RDONLY)
+                except OSError:
+                    continue
+                try:
+                    os.fsync(fd)
+                    n += 1
+                except OSError:
+                    pass
+                finally:
+                    os.close(fd)
+    return n
+
+
 def peak_bytes() -> int:
     import jax
     peaks = [(dv.memory_stats() or {}).get("peak_bytes_in_use", 0)

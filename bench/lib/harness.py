@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import gc
 import json
+import os
 import shutil
 import sys
 import time
@@ -69,6 +70,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         bench=None, conf=None, cellp=None, mix=None, chips=None,
         need_accelerator=True, t_start=None, out=None, err=None):
     t_start = t_start if t_start is not None else time.perf_counter()
+    wall_start = time.time() - (time.perf_counter() - t_start)
     out = out or sys.stdout
     err = err or sys.stderr
     bench = bench or spec.benchmark()
@@ -76,16 +78,17 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     conf = conf or spec.config(w["config"])
     mix = mix or spec.traffic(w["traffic"])
     cellp = cellp or spec.cell(workload)
-    dev = device.check(w["chips"] if chips is None else chips,
-                       need_accelerator)
-    device.enable_compile_cache()
+    chips = w["chips"] if chips is None else chips
+    dev = device.check(chips, need_accelerator)
+    cache_dir = device.enable_compile_cache()
+    mesh = serve.serving_mesh(chips)
 
     def log(msg):
         print(f"bench: {msg} at {time.perf_counter() - t_start:.2f} s",
               file=err, flush=True)
 
     log("device found")
-    engine = serve.build_engine(conf, cellp, seed)
+    engine = serve.build_engine(conf, cellp, seed, mesh)
     log("engine built")
     serve.warm(engine, serve.warm_lengths(mix, cellp))
     log("warm-up done")
@@ -93,6 +96,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     driver = serve.Driver(engine, items, seed, cellp.get("clients"))
     driver.ramp(cellp.get("ramp_steps", 0))
     log("clients started")
+    synced = device.flush_writes(
+        [cache_dir, os.environ.get("TPU_LOG_DIR", "")], wall_start)
+    log(f"{synced} files written in set-up synced")
     stats0 = dict(engine.stats)
 
     tr = {}
@@ -127,6 +133,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     if trace:
         summary = btrace.reduce(trace_dir)
         ctx = metrics.Context(conf=conf, cell=cellp, peaks=dev["peaks"],
+                              chips=chips,
                               trace=summary, driver=driver,
                               window=(t0, t1), traced=(tr["t0"], tr["t1"]),
                               stats=(tr["stats0"], tr["stats1"]))
@@ -150,7 +157,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     gc.collect()
     log("program state freed")
     checks, result["correct"] = check.judge(
-        cellp, check.served_gap(conf, cellp, seed, samples),
+        cellp, check.served_gap(conf, cellp, seed, samples, mesh),
         check.tokens(samples), compiles, failed)
     result["checks"] = checks
     log("check done")

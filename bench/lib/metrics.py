@@ -21,6 +21,8 @@ class Context:
     window: Tuple[float, float]     # harness clock
     traced: Tuple[float, float]     # harness clock
     stats: Tuple[dict, dict]        # engine stats at trace start / stop
+    chips: int = 1                  # chips the cell serves over, each
+    #                                 layer split over all of them
 
     @property
     def mc(self) -> dict:

@@ -6,6 +6,8 @@ gives for the configuration at its target bits per weight, the layout the
 program serves. Every leaf is drawn in one jitted call: packed sign words
 uniform over uint32, channel scales that keep each packed linear at its
 input's scale, and the rest as ``assumed`` in the configuration's file says.
+A cell on several chips draws each leaf into the shards the program's
+``sharding.rules`` give it on the serving mesh.
 The same seed gives the same bits, so the reference can draw them again
 after the program's state is gone.
 """
@@ -52,7 +54,9 @@ def _leaf(key, path, sds, conf):
         std = conf["assumed"]["embed_std"] / math.sqrt(shape[-1])
         return (std * jax.random.normal(key, shape, jnp.float32)
                 ).astype(dtype)
-    if leaf == "w" and names[-2] == "lm_head":
+    if leaf == "w" and len(shape) >= 2:
+        # a linear kept in full precision (the untied head, a router, an
+        # MLA up-projection): N(0, 1)/sqrt(d_in), d_in = shape[-2]
         std = 1.0 / math.sqrt(shape[-2])
         return (std * jax.random.normal(key, shape, jnp.float32)
                 ).astype(dtype)
@@ -62,8 +66,20 @@ def _leaf(key, path, sds, conf):
     raise ValueError(f"no rule to draw weight leaf {'/'.join(names)}")
 
 
-def make_weights(conf: dict, seed: int):
-    """The whole parameter tree on the device, from the seed."""
+def shardings(conf: dict, mesh):
+    """Where each leaf lives on the program's serving mesh: the
+    program's own rules (``sharding.rules``, its serving policy)."""
+    from repro.sharding import rules
+    specs = rules.param_pspecs(model_config(conf), weight_shapes(conf), mesh,
+                               rules.SERVE)
+    return rules.to_shardings(mesh, specs)
+
+
+def make_weights(conf: dict, seed: int, mesh=None):
+    """The whole parameter tree on the device, from the seed; with a
+    mesh, each leaf drawn straight into its shards, so no chip ever
+    holds the whole tree. The bits are the same either way: JAX's
+    threefry is partitionable (``jax_threefry_partitionable``)."""
     shapes = weight_shapes(conf)
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     # sizes of each packed linear, read from its own packed leaves
@@ -88,4 +104,7 @@ def make_weights(conf: dict, seed: int):
                 out.append(_leaf(k, path, sds, conf))
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    return jax.jit(draw)(key_for(seed, 1))
+    if mesh is None:
+        return jax.jit(draw)(key_for(seed, 1))
+    return jax.jit(draw, out_shardings=shardings(conf, mesh))(
+        key_for(seed, 1))

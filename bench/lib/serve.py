@@ -45,20 +45,31 @@ class StepLog:
     kv_rows: int                    # sum over decoded slots of rows read
 
 
-def build_engine(conf: dict, cellp: dict, seed: int):
-    """The cell's engine over packed weights drawn from the seed."""
+def serving_mesh(chips: int):
+    """The program's tensor-parallel serving mesh over the cell's chips
+    (``launch.mesh.make_serving_mesh``); None for one chip."""
+    if chips <= 1:
+        return None
+    from repro.launch.mesh import make_serving_mesh
+    return make_serving_mesh(chips)
+
+
+def build_engine(conf: dict, cellp: dict, seed: int, mesh=None):
+    """The cell's engine over packed weights drawn from the seed; with a
+    mesh, the weights drawn into their shards and the engine serving
+    tensor-parallel over it."""
     from repro import api
     from repro.core.pipeline import QuantConfig
     from repro.serve.engine import ServeConfig
     cfg = bmodel.model_config(conf)
-    params = bmodel.make_weights(conf, seed)
+    params = bmodel.make_weights(conf, seed, mesh)
     nq = api.NanoQuantModel(params, cfg,
                             QuantConfig(target_bpw=conf["target_bpw"]), {})
     scfg = ServeConfig(greedy=True, page_size=cellp["page_size"],
                        kv_pool_pages=cellp.get("kv_pool_pages"),
                        page_watermark=cellp.get("page_watermark", 0))
     return nq.engine(scfg, max_batch=cellp["max_batch"],
-                     max_len=cellp["max_len"], seed=0)
+                     max_len=cellp["max_len"], seed=0, mesh=mesh)
 
 
 def warm_lengths(mix: dict, cellp: dict) -> List[int]:

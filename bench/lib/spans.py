@@ -84,11 +84,12 @@ def _load_cached(path: str, mtime: float) -> Tuple[Span, ...]:
     return tuple(load(path))
 
 
-def of_run(trace_dir=RUN_TRACE_DIR) -> List[Span]:
-    """The spans of the run's trace, read once per trace file; none
-    where the run left no trace."""
+def of_run(trace_dir=None) -> List[Span]:
+    """The spans of the run's trace (`trace_dir`, else the harness's
+    ``RUN_TRACE_DIR`` as it stands when called), read once per trace
+    file; none where the run left no trace."""
     try:
-        path = trace.find_xplane(trace_dir)
+        path = trace.find_xplane(trace_dir or RUN_TRACE_DIR)
     except FileNotFoundError:
         return []
     return list(_load_cached(path, os.path.getmtime(path)))

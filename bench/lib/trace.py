@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 import glob
 import os
+import re
 from typing import Dict, List, Tuple
 
 OPS_LINE = "XLA Ops"
@@ -37,6 +38,7 @@ class Op:
     start: int                      # ns
     dur: int                        # ns
     device: int
+    opcode: str = ""                # the HLO instruction's opcode
 
 
 @dataclasses.dataclass
@@ -116,6 +118,17 @@ def op_name(text: str) -> str:
     return text.split(" = ", 1)[0].lstrip("%")
 
 
+OPCODE = re.compile(r"\s([a-z][a-z0-9-]*)\(")
+
+
+def opcode(text: str) -> str:
+    """An op event's opcode: the word after its shape that opens the
+    operand list (``custom-call`` above; an op's name need not say it,
+    as ``%psum.20 = bf16[9,1,8192]{...} all-reduce(...)``)."""
+    m = OPCODE.search(" " + text.split(" = ", 1)[-1])
+    return m.group(1) if m else ""
+
+
 def union_ns(intervals, lo: int, hi: int) -> int:
     """Length of the union of [s, e) intervals clipped to [lo, hi)."""
     total, cur_s, cur_e = 0, None, None
@@ -185,7 +198,8 @@ def load(path: str) -> Summary:
                             mods_sorted[j][1] + mods_sorted[j][2]:
                         mod = mods_sorted[j][0]
                     ops.append(Op(op_name(ev.name), mod, s,
-                                  int(ev.duration_ns), dev))
+                                  int(ev.duration_ns), dev,
+                                  opcode(ev.name)))
         else:
             for ln in plane.lines:
                 for ev in ln.events:

@@ -1,8 +1,8 @@
 """Model FLOP/s utilization of decoding: the model FLOPs of every token
 decoded in the traced window (packed linears, head, attention over each
 token's real context; ``work/decode_step.py``) over the traced window's
-length and the chip's peak bf16 FLOP/s. Prefill tokens are not
-counted."""
+length and the peak bf16 FLOP/s of the cell's chips. Prefill tokens are
+not counted."""
 from bench.lib import spec
 
 step = spec.load_module("work", "decode_step")
@@ -20,4 +20,4 @@ def read(ctx):
                 flops += step.token_flops(ctx.mc, lin, rows)
     if flops <= 0:
         return None
-    return 100.0 * flops / (hi - lo) / ctx.peaks["bf16_flops"]
+    return 100.0 * flops / (hi - lo) / (ctx.peaks["bf16_flops"] * ctx.chips)

@@ -76,3 +76,15 @@ def test_recorded_roofline_share_is_a_share():
     share = 100 * facts["decode_steps"] * bound / \
         s.kernel_s("nq_fused_lowrank_matmul", "decode_fn")
     assert 0 < share <= 100
+
+
+def test_recorded_ops_carry_their_opcode():
+    """Each op keeps the opcode of its HLO text beside its name: the
+    kernels are custom calls, the async copies start and done ops."""
+    s = trace.load(str(DATA / "decode3.xplane.pb"))
+    by_name = {o.name.split(".")[0]: o.opcode for o in s.ops}
+    assert by_name["nq_paged_attention"] == "custom-call"
+    assert by_name["nq_fused_lowrank_matmul"] == "custom-call"
+    assert by_name["copy-start"] == "copy-start"
+    assert by_name["while"] == "while"
+    assert all(o.opcode for o in s.ops)
